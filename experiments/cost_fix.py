@@ -20,6 +20,9 @@ Run AFTER the main sweep:  PYTHONPATH=src python experiments/cost_fix.py
 import os
 import sys
 
+# a CPU-only tool, and so are the dry-run children that inherit this
+# environment: never take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import dataclasses

@@ -38,10 +38,12 @@ class TeacherDataset:
     def __init__(self, n: int, batch: int, seed: int = 0):
         self.W = gaussian_teacher(n, seed)
         self.batch = batch
-        self._make = jax.jit(lambda s: teacher_batch(self.W, batch, s))
+        # W is an argument, not a closure: a closed-over array is baked
+        # into the program as a constant (1 GiB at n=16384)
+        self._make = jax.jit(teacher_batch, static_argnums=1)
 
     def __call__(self, step: int):
-        return self._make(jnp.int32(step))
+        return self._make(self.W, self.batch, jnp.int32(step))
 
 
 def lm_token_batch(vocab: int, batch: int, seq: int, seed: int,

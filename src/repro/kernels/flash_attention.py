@@ -10,8 +10,9 @@ scratch; only q/k/v reads and the final output write touch HBM.
 GQA is handled by folding query heads of one kv group into the q block's
 row dimension (rows = q_heads_per_group * block_q tokens).
 
-TARGET is TPU (pl.pallas_call + BlockSpec); validated interpret=True
-against kernels/ref.py on CPU.
+TARGET is TPU (pl.pallas_call + BlockSpec): compile-tested for TPU v5e
+(tests/test_tpu_compile.py) and run compiled on the chip by
+``chip_smoke.py``; CPU tests run it interpret=True against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -23,9 +24,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.phantom_fused import KernelConfigError
-from repro.parallel.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
 
 NEG_INF = -1e30
 
@@ -57,20 +55,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def body(ik, carry):
         m, l, acc = carry
-        ks = pl.load(k_ref, (pl.dslice(ik * block_k, block_k),
-                             slice(None))).astype(jnp.float32)
-        vs = pl.load(v_ref, (pl.dslice(ik * block_k, block_k),
-                             slice(None))).astype(jnp.float32)
+        ks = k_ref[pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
+        vs = v_ref[pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                  # [bq*hg, block_k]
         if causal:
-            q_pos = (iq * block_q
-                     + jax.lax.broadcasted_iota(jnp.int32,
-                                                (bq, hg), 0)).reshape(-1)
-            k_pos = ik * block_k + jax.lax.iota(jnp.int32, block_k)
-            mask = k_pos[None, :] <= q_pos[:, None]
-            s = jnp.where(mask, s, NEG_INF)
+            # row r of the folded block is token iq*block_q + r // hg, so
+            # k_pos <= q_pos  <=>  (k_pos - iq*block_q) * hg <= r.  2-D
+            # iotas keep the mask free of in-kernel reshapes, which Mosaic
+            # refuses for GQA (hg > 1)
+            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            k_rel = ik * block_k - iq * block_q + cols
+            s = jnp.where(k_rel * hg <= rows, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m - m_new)
@@ -132,7 +130,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         out_specs=pl.BlockSpec((None, bq, Hg, hd),
                                lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KV, S, Hg, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kg, vg)

@@ -22,8 +22,10 @@ tile multiple with zeros (exact for a matmul) and sliced back, so
 non-multiple-of-128 shapes are legal.  MXU-aligned tile defaults
 (128x128x128).
 
-TARGET is TPU (compiled via pl.pallas_call + BlockSpec); this container
-is CPU-only so tests run interpret=True against ref.py.
+TARGET is TPU (compiled via pl.pallas_call + BlockSpec).  The kernels
+are compile-tested for TPU v5e at the paper-ffn-16k per-rank shapes
+(tests/test_tpu_compile.py) and run compiled on the chip by
+``chip_smoke.py``; CPU tests run them interpret=True against ref.py.
 """
 from __future__ import annotations
 
@@ -33,10 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.parallel.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
 
 # Per-core VMEM on current TPU generations (v4/v5e/v5p ~= 16 MiB); tile
 # configs whose working set exceeds this cannot be scheduled on-chip.
@@ -175,7 +173,7 @@ def phantom_fused_matmul(x, L, g, D, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, L, g, D)
@@ -228,7 +226,7 @@ def matmul_nt(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Jp), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -261,7 +259,7 @@ def matmul_tn(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Ip, Np), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

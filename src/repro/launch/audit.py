@@ -82,6 +82,8 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return audit(args)
 
 

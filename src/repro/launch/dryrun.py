@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# a CPU-only tool, and so are the --all children that inherit this
+# environment: never take the chip from the process that owns it
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks them on first init.
 
 """Multi-pod dry-run (deliverable e).
 
@@ -368,6 +371,8 @@ def main():
                          "the §Perf hillclimb")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     overrides = parse_sets(getattr(args, "set"))
     if args.cost_fix:
         cost_fix(args.arch, args.shape, args.impl, args.cost_fix,

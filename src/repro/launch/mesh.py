@@ -8,16 +8,10 @@ import; tests/benches use small local meshes.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 — explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: make_mesh has no axis_types parameter
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -42,24 +36,19 @@ def make_production_mesh(*, multi_pod: bool = False, pp: int = 1):
 
 
 def make_local_mesh(dp: int = 2, tp: int = 4, pp: int = 1):
-    """Small mesh over host devices (tests/benches/examples).
+    """Small mesh over the first dp*tp*pp host devices (tests, benches,
+    examples, the chip smoke run).
 
     ``pp > 1`` adds a leading ``pipe`` axis (pipeline stages); meshes
-    without one behave exactly as before (pp=1).  dp then tp shrink to
-    fit the host (the historical contract); pp is a model property
-    (stage count) and is never silently changed — too many stages for
-    the host raises.
+    without one behave exactly as before (pp=1).  A request larger than
+    the host raises: shrinking it would run a different configuration
+    (a 4-chip mesh on one chip) than the caller asked for.
     """
     n = len(jax.devices())
     pp = max(pp, 1)
-    if pp > n:
-        raise ValueError(f"pp={pp} pipeline stages need at least pp "
-                         f"devices; host has {n}")
     if dp * tp * pp > n:
-        dp = max(1, n // (tp * pp))
-        if dp * tp * pp > n:
-            tp = max(1, n // pp)
-            dp = 1
+        raise ValueError(f"mesh dp={dp} x tp={tp} x pp={pp} needs "
+                         f"{dp * tp * pp} devices; host has {n}")
     if pp > 1:
         return _make_mesh((pp, dp, tp), ("pipe", "data", "model"))
     return _make_mesh((dp, tp), ("data", "model"))

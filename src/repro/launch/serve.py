@@ -160,6 +160,8 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.dp * args.tp} "
             + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.launch.obs import obs_session
     with obs_session(args.trace_out, args.metrics_out,

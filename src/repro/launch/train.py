@@ -232,9 +232,9 @@ def main():
                          "specs (xla)")
     ap.add_argument("--overlap", default=None, choices=("tpu", "gpu"),
                     help="append the async-collective + latency-hiding-"
-                         "scheduler XLA flag recipe for the given "
-                         "platform (comm/compute overlap of the ghost "
-                         "all-gather; no-op semantics on cpu)")
+                         "scheduler flag recipe for the given platform "
+                         "(LIBTPU_INIT_ARGS on tpu, XLA_FLAGS on gpu): "
+                         "comm/compute overlap of the ghost all-gather")
     args = ap.parse_args()
     if args.overlap:
         from repro.parallel.compat import enable_comm_overlap
@@ -251,6 +251,8 @@ def main():
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={ndev} "
             + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.elastic:
         with obs_session(args.trace_out, args.metrics_out,

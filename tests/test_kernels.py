@@ -295,9 +295,10 @@ def test_comm_overlap_flags():
     assert set(COMM_OVERLAP_FLAGS) == {"cpu", "gpu", "tpu"}
 
     import os
-    saved = os.environ.get("XLA_FLAGS")
+    saved = {v: os.environ.get(v) for v in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")}
     try:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ.pop("LIBTPU_INIT_ARGS", None)
         applied = enable_comm_overlap("gpu")
         assert applied == comm_overlap_flags("gpu")
         first = os.environ["XLA_FLAGS"]
@@ -306,11 +307,18 @@ def test_comm_overlap_flags():
         assert enable_comm_overlap("gpu") == ""   # idempotent: no re-add
         assert os.environ["XLA_FLAGS"] == first
         assert enable_comm_overlap("cpu") == ""   # cpu is a no-op
+        # the TPU recipe goes to libtpu: XLA_FLAGS aborts on those flags
+        assert enable_comm_overlap("tpu") == comm_overlap_flags("tpu")
+        assert os.environ["LIBTPU_INIT_ARGS"] == comm_overlap_flags("tpu")
+        assert os.environ["XLA_FLAGS"] == first
+        assert enable_comm_overlap("tpu") == ""   # idempotent: no re-add
+        assert os.environ["LIBTPU_INIT_ARGS"] == comm_overlap_flags("tpu")
     finally:
-        if saved is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = saved
+        for var, val in saved.items():
+            if val is None:
+                os.environ.pop(var, None)
+            else:
+                os.environ[var] = val
 
 
 def test_with_kernel_backend_config():
