@@ -36,6 +36,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, PHANTOM_KINDS
+from repro.obs import scopes
 from repro.parallel.axes import MeshAxes, resolve_spec
 from repro.parallel.params import (abstract, is_decl, materialize, specs,
                                    stack)
@@ -219,16 +220,20 @@ def make_ffn_train_step(cfg: ModelConfig, mesh, optimizer,
 
     def step_fn(params, opt_state, step, x, y):
         def loss_fn(p):
-            out = ffn_apply(cfg, axes, p, x)
-            # local share only — outputs are fully sharded (batch over dp,
-            # features over tp) so the local sse IS this device's unique
-            # contribution; cross-device sums happen via grad psums.
-            return jnp.sum(jnp.square(out - y)) / (global_batch * n)
+            with jax.named_scope(scopes.MODEL):
+                out = ffn_apply(cfg, axes, p, x)
+                # local share only — outputs are fully sharded (batch over
+                # dp, features over tp) so the local sse IS this device's
+                # unique contribution; cross-device sums happen via grad
+                # psums.
+                return jnp.sum(jnp.square(out - y)) / (global_batch * n)
 
         sse_local, grads = jax.value_and_grad(loss_fn)(params)
         loss = lax.psum(sse_local, axes.all_names)
         grads = jax.tree.map(lambda g: lax.psum(g, axes.dp_names), grads)
-        params, opt_state = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope(scopes.OPTIMIZER):
+            params, opt_state = optimizer.update(grads, opt_state, params,
+                                                 step)
         return params, opt_state, loss
 
     pspecs = jax.tree.map(lambda s: resolve_spec(s, axes), specs(decls))
@@ -272,14 +277,15 @@ def _make_ffn_pipeline_train_step(cfg: ModelConfig, mesh, optimizer,
         y_mb = split_microbatches(y, M)
 
         def loss_fn(p):
-            stage_fn = make_ffn_stage_fn(cfg, axes, p)
-            y_hat, _aux = pipeline_run(stage_fn, x_mb, axes,
-                                       unroll=not cfg.scan_layers)
-            sse = jnp.sum(jnp.square(y_hat - y_mb))
-            if axes.pp > 1:
-                is_last = lax.axis_index(axes.pp_name) == axes.pp - 1
-                sse = jnp.where(is_last, sse, jnp.float32(0))
-            return sse / (global_batch * n)
+            with jax.named_scope(scopes.MODEL):
+                stage_fn = make_ffn_stage_fn(cfg, axes, p)
+                y_hat, _aux = pipeline_run(stage_fn, x_mb, axes,
+                                           unroll=not cfg.scan_layers)
+                sse = jnp.sum(jnp.square(y_hat - y_mb))
+                if axes.pp > 1:
+                    is_last = lax.axis_index(axes.pp_name) == axes.pp - 1
+                    sse = jnp.where(is_last, sse, jnp.float32(0))
+                return sse / (global_batch * n)
 
         sse_local, grads = jax.value_and_grad(loss_fn)(params)
         loss = lax.psum(sse_local, axes.all_names)
@@ -289,7 +295,9 @@ def _make_ffn_pipeline_train_step(cfg: ModelConfig, mesh, optimizer,
         red = axes.dp_names + (axes.pp_names if mixed else ())
         if red:
             grads = jax.tree.map(lambda g: lax.psum(g, red), grads)
-        params, opt_state = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope(scopes.OPTIMIZER):
+            params, opt_state = optimizer.update(grads, opt_state, params,
+                                                 step)
         return params, opt_state, loss
 
     pspecs = jax.tree.map(lambda s: resolve_spec(s, axes), specs(decls))

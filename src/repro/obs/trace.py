@@ -14,6 +14,14 @@ manually-advanced clock — the elastic runtime's ``VirtualClock`` — two
 identical schedules produce byte-identical traces, which is what the
 golden-schema tests pin.
 
+The profiler's clock: an enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` of the same name for each span, from
+``begin`` to ``end``.  While a ``jax.profiler`` session runs, the spans
+then land on the capture's host plane, on the device trace's clock, so
+an idle gap on the chip reads against what the host was doing; with no
+session running the annotation records nothing.  A disabled tracer
+creates none.
+
 The ledger cross-link: a span that timed a computation the energy
 ledger also priced calls ``span.link_ledger(entry)``; the span's args
 then carry the entry name, the measured wall fields and the predicted
@@ -33,6 +41,8 @@ import time
 from contextlib import contextmanager
 from typing import Callable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 TRACE_SCHEMA = "chrome-trace-event"
 
 
@@ -40,7 +50,7 @@ class Span:
     """One open (or closed) interval; mutate args via ``annotate``."""
 
     __slots__ = ("name", "cat", "span_id", "tid", "ts_us", "dur_us",
-                 "args", "_tracer")
+                 "args", "_tracer", "_annotation")
 
     def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
                  span_id: str, tid: int, ts_us: float):
@@ -52,6 +62,7 @@ class Span:
         self.ts_us = ts_us
         self.dur_us: Optional[float] = None
         self.args: dict = {}
+        self._annotation: Optional[TraceAnnotation] = None
 
     def annotate(self, **kw) -> "Span":
         self.args.update(kw)
@@ -153,11 +164,16 @@ class Tracer:
                       self._now_us())
             sp.args.update(args)
             self._open.append(sp)
+        sp._annotation = TraceAnnotation(name)
+        sp._annotation.__enter__()
         return sp
 
     def end(self, span: Span) -> Span:
         if not self.enabled or span is _NULL_SPAN:
             return span
+        if span._annotation is not None:
+            span._annotation.__exit__(None, None, None)
+            span._annotation = None
         with self._lock:
             span.dur_us = max(self._now_us() - span.ts_us, 0.0)
             if span in self._open:

@@ -96,6 +96,11 @@ class ElasticConfig:
     # anomaly the energy-drift watchdog must trip on)
     slow_steps: Tuple[int, ...] = ()
     slow_factor: float = 6.0
+    # report each step to the watchdog, straggler detector and step
+    # histogram as virtual_dt seconds (a slow step as slow_factor x
+    # that, with no sleep) instead of its wall time: the same run then
+    # feeds them the same numbers on any machine
+    virtual_step_time: bool = False
 
 
 @dataclass
@@ -536,7 +541,8 @@ def run_elastic(cfg: ElasticConfig, *, ledger=None,
 
         x, y = ds(step)
         step_fn = rt["step_fn"]
-        if step in cfg.slow_steps:
+        slow = step in cfg.slow_steps
+        if slow and not cfg.virtual_step_time:
             base = (watchdog.reference_s()
                     if watchdog is not None else None)
             if not base:
@@ -567,6 +573,8 @@ def run_elastic(cfg: ElasticConfig, *, ledger=None,
         phases[-1].steps += 1
         step += 1
         dt_s = meter.times_us[-1] / 1e6
+        if cfg.virtual_step_time:
+            dt_s = cfg.virtual_dt * (cfg.slow_factor if slow else 1.0)
         metrics.counter("train_steps_total",
                         "executed training steps").inc(
                             suite="elastic")

@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.model import (AUX_LOSS_WEIGHT, forward_train,
                                 forward_train_pipeline, model_decls)
-from repro.obs import get_metrics, get_tracer
+from repro.obs import get_metrics, get_tracer, scopes
 from repro.parallel.axes import MeshAxes, resolve_spec
 from repro.parallel.compat import shard_map
 from repro.parallel.grads import reduce_grads
@@ -80,33 +80,37 @@ def make_train_step(cfg: ModelConfig, mesh, optimizer, *,
         # accumulation path's mean-of-per-microbatch-means only differs
         # on ragged batches, where per-token weighting is the more
         # faithful objective, so the pipeline keeps it.
-        sum_loss, n_valid, aux = forward_train_pipeline(
-            cfg, axes, params, batch, microbatches)
-        red = axes.pp_names + axes.dp_names
-        nv_g = lax.psum(n_valid, red).astype(jnp.float32)
-        nv_g = jnp.maximum(nv_g, 1.0)
-        # aux is a per-microbatch MEAN summed over the M wavefront
-        # microbatches — divide by M so the effective aux weight matches
-        # the accumulation path (which averages grads over microbatches)
-        mb = max(microbatches, 1)
-        obj = (sum_loss / nv_g
-               + AUX_LOSS_WEIGHT * aux / (axes.dp * mb)) / axes.tp
-        ce_report = lax.psum(sum_loss, red) / nv_g
-        return obj, ce_report
+        with jax.named_scope(scopes.MODEL):
+            sum_loss, n_valid, aux = forward_train_pipeline(
+                cfg, axes, params, batch, microbatches)
+            red = axes.pp_names + axes.dp_names
+            nv_g = lax.psum(n_valid, red).astype(jnp.float32)
+            nv_g = jnp.maximum(nv_g, 1.0)
+            # aux is a per-microbatch MEAN summed over the M wavefront
+            # microbatches — divide by M so the effective aux weight
+            # matches the accumulation path (which averages grads over
+            # microbatches)
+            mb = max(microbatches, 1)
+            obj = (sum_loss / nv_g
+                   + AUX_LOSS_WEIGHT * aux / (axes.dp * mb)) / axes.tp
+            ce_report = lax.psum(sum_loss, red) / nv_g
+            return obj, ce_report
 
     def loss_fn(params, batch):
-        sum_loss, n_valid, aux = forward_train(cfg, axes, params, batch)
-        # Differentiate each device's UNIQUE share of the global objective:
-        # psum-ing the scalar pre-grad would inflate grads by the device
-        # count (psum's transpose under shard_map is psum).  The xent sum
-        # is replicated across tp (every tp rank computes all local
-        # tokens), hence the 1/tp; cross-dp sums happen in reduce_grads.
-        nv_g = lax.psum(n_valid, axes.dp_names).astype(jnp.float32)
-        nv_g = jnp.maximum(nv_g, 1.0)
-        obj = (sum_loss / nv_g
-               + AUX_LOSS_WEIGHT * aux / axes.dp) / axes.tp
-        ce_report = lax.psum(sum_loss, axes.dp_names) / nv_g
-        return obj, ce_report
+        with jax.named_scope(scopes.MODEL):
+            sum_loss, n_valid, aux = forward_train(cfg, axes, params, batch)
+            # Differentiate each device's UNIQUE share of the global
+            # objective: psum-ing the scalar pre-grad would inflate grads
+            # by the device count (psum's transpose under shard_map is
+            # psum).  The xent sum is replicated across tp (every tp rank
+            # computes all local tokens), hence the 1/tp; cross-dp sums
+            # happen in reduce_grads.
+            nv_g = lax.psum(n_valid, axes.dp_names).astype(jnp.float32)
+            nv_g = jnp.maximum(nv_g, 1.0)
+            obj = (sum_loss / nv_g
+                   + AUX_LOSS_WEIGHT * aux / axes.dp) / axes.tp
+            ce_report = lax.psum(sum_loss, axes.dp_names) / nv_g
+            return obj, ce_report
 
     def step_fn(params, opt_state, step, batch):
         if pipelined:
@@ -135,11 +139,14 @@ def make_train_step(cfg: ModelConfig, mesh, optimizer, *,
             ce = ce / microbatches
 
         grads = reduce_grads(grads, decls, axes)
-        gnorm = _global_norm(grads, decls, axes)
-        if grad_clip > 0:
-            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-9))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        params, opt_state = optimizer.update(grads, opt_state, params, step)
+        with jax.named_scope(scopes.OPTIMIZER):
+            gnorm = _global_norm(grads, decls, axes)
+            if grad_clip > 0:
+                scale = jnp.minimum(1.0,
+                                    grad_clip / jnp.maximum(gnorm, 1e-9))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+            params, opt_state = optimizer.update(grads, opt_state, params,
+                                                 step)
         return params, opt_state, {"loss": ce, "grad_norm": gnorm}
 
     pspecs = jax.tree.map(lambda s: resolve_spec(s, axes), specs(decls))

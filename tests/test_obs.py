@@ -102,6 +102,49 @@ def test_null_tracer_is_free_noop():
     assert get_tracer().enabled is False or get_tracer() is not None
 
 
+def _host_events(profile_dir):
+    """(name, start ns, end ns) of every event on a capture's host
+    plane."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for line in plane.lines for ev in line.events]
+    return out
+
+
+def test_tracer_spans_land_on_the_profiler_clock(tmp_path):
+    """An enabled tracer's span shows on a profiler capture's host
+    plane, inside the annotation that encloses it; a disabled tracer's
+    span adds no event."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    on, off = Tracer(), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("test.outer"):
+            with on.span("obs/enabled", cat="test"):
+                sum(range(1000))
+            with off.span("obs/disabled", cat="test"):
+                sum(range(1000))
+    finally:
+        jax.profiler.stop_trace()
+    evs = _host_events(str(tmp_path))
+    (_, o0, o1), = [e for e in evs if e[0] == "test.outer"]
+    (_, s0, s1), = [e for e in evs if e[0] == "obs/enabled"]
+    assert o0 <= s0 <= s1 <= o1
+    assert not [e for e in evs if e[0] == "obs/disabled"]
+    assert [e["name"] for e in span_events(on.to_chrome())] == [
+        "obs/enabled"]
+    assert len(off) == 0
+
+
 def test_set_tracer_restores_previous():
     tr = Tracer()
     prev = set_tracer(tr)
@@ -503,13 +546,16 @@ def test_elastic_trace_matches_recovery_account(tmp_path):
 
 def test_elastic_slow_step_trips_watchdog(tmp_path):
     """An injected slow step trips the watchdog mid-run (anomaly row in
-    the ledger); the same config without the injection stays silent."""
+    the ledger); the same config without the injection stays silent.
+    Both runs report virtual step times, so the host's load cannot
+    make a spike of its own."""
     from repro.train.elastic import run_elastic
 
     ledger = Ledger(run="t")
     wd = EnergyDriftWatchdog(ledger=ledger, name="t")
     res = run_elastic(_elastic_cfg(tmp_path, max_steps=16,
-                                   slow_steps=(12,)),
+                                   slow_steps=(12,),
+                                   virtual_step_time=True),
                       watchdog=wd, ledger=ledger,
                       log_fn=lambda *a, **k: None)
     assert not res.aborted
@@ -518,7 +564,8 @@ def test_elastic_slow_step_trips_watchdog(tmp_path):
 
     wd2 = EnergyDriftWatchdog(name="t2")
     res2 = run_elastic(_elastic_cfg(tmp_path, max_steps=16,
-                                    workdir=str(tmp_path / "clean")),
+                                    workdir=str(tmp_path / "clean"),
+                                    virtual_step_time=True),
                        watchdog=wd2, log_fn=lambda *a, **k: None)
     assert not res2.aborted
     assert wd2.trips == []
