@@ -309,7 +309,9 @@ class ModelConfig:
                                     # -1 = unrolled (dry-run cost analysis:
                                     # XLA counts scan bodies once)
     scan_layers: bool = True        # False = python-loop layer stack
-                                    # (dry-run cost analysis only)
+                                    # and 1F1B tick loop (dry-run cost
+                                    # analysis only); the paper-FFN
+                                    # layer stack is always unrolled
     fsdp_gather_quant: bool = False  # int8-quantize FSDP weight gathers
                                      # (serving: halves gather wire bytes)
     attn_ring_gather_kv: bool = False  # ring mode: gather KV once instead

@@ -129,30 +129,26 @@ def ffn_apply(cfg: ModelConfig, axes: MeshAxes, params, x):
         raise ValueError("pipelined FFN configs run through "
                          "make_ffn_train_step / make_ffn_pipeline_probe; "
                          "ffn_apply is the single-stage path")
+    return _apply_stack(cfg, axes, ffn_strategy(cfg, axes.tp),
+                        params["layers"], x)
+
+
+def _apply_stack(cfg, axes, st, stack_params, x):
+    """Apply an [L, ...] layer stack to a feature shard, one layer at a
+    time by static index.
+
+    Paper-FFN stacks are short (L in {2, 6}), so they always run
+    unrolled, whatever ``cfg.scan_layers`` says: XLA then casts each
+    layer's weights to bf16 inside its matmuls, hands the optimizer each
+    layer's gradient where its matmul left it, and drops the first
+    layer's unused input gradient.  A ``lax.scan`` would cast the whole
+    stack up front, zero-fill an [L, ...] gradient buffer, and compute
+    that input gradient in its one backward body."""
     act = _act(cfg.mlp)
-    st = ffn_strategy(cfg, axes.tp)
-
-    def body(carry, layer):
-        z = st.apply_shard(layer, carry, axes)
-        return act(z), None
-
-    # scan_layers=False unrolls the layer loop (telemetry/dry-run cost
-    # accounting: XLA's cost analysis counts a scan body once)
-    unroll = 1 if cfg.scan_layers else max(cfg.num_layers, 1)
-    x, _ = lax.scan(body, x, params["layers"], unroll=unroll)
-    return x
-
-
-def _apply_stage_stack(cfg, axes, st, stack_params, x):
-    """Apply one stage's [L_loc, ...] layer stack to a feature shard."""
-    act = _act(cfg.mlp)
-
-    def body(carry, layer):
-        return act(st.apply_shard(layer, carry, axes)), None
-
-    L_loc = cfg.num_layers // cfg.pipeline.stages
-    unroll = 1 if cfg.scan_layers else max(L_loc, 1)
-    x, _ = lax.scan(body, x, stack_params, unroll=unroll)
+    depth = jax.tree.leaves(stack_params)[0].shape[0]
+    for i in range(depth):
+        layer = jax.tree.map(lambda a: a[i], stack_params)
+        x = act(st.apply_shard(layer, x, axes))
     return x
 
 
@@ -171,7 +167,7 @@ def make_ffn_stage_fn(cfg: ModelConfig, axes: MeshAxes, params):
             for s in range(S):
                 sp = (params[f"stage{s}"] if mixed
                       else jax.tree.map(lambda a: a[s], params["stages"]))
-                x = _apply_stage_stack(cfg, axes, sts[s], sp, x)
+                x = _apply_stack(cfg, axes, sts[s], sp, x)
             return x, jnp.float32(0)
         return stage_fn
 
@@ -181,14 +177,14 @@ def make_ffn_stage_fn(cfg: ModelConfig, axes: MeshAxes, params):
         local = jax.tree.map(lambda a: a[0], params["stages"])
 
         def stage_fn(x):
-            return (_apply_stage_stack(cfg, axes, sts[0], local, x),
+            return (_apply_stack(cfg, axes, sts[0], local, x),
                     jnp.float32(0))
         return stage_fn
 
     s_idx = lax.axis_index(axes.pp_name)
     branches = [
-        (lambda x, s=s: _apply_stage_stack(cfg, axes, sts[s],
-                                           params[f"stage{s}"], x))
+        (lambda x, s=s: _apply_stack(cfg, axes, sts[s],
+                                     params[f"stage{s}"], x))
         for s in range(S)]
 
     def stage_fn(x):

@@ -12,7 +12,8 @@ objects, ``analyze_compiled`` reads what the compiler actually lowered —
 
 Caveat that the dry-run already documents: XLA counts each ``scan`` /
 while-loop body ONCE, so for exact totals compile with layers unrolled
-(``cfg.scan_layers=False``; the FFN probe and the bench suites do).
+(``cfg.scan_layers=False``; the paper-FFN layer stack always runs
+unrolled, and the pipelined FFN probe unrolls its tick loop too).
 """
 from __future__ import annotations
 

@@ -6,9 +6,10 @@ params AND inputs, no optimizer) for the strategy ``cfg`` selects, as one
 ``core/ffn.make_ffn_train_step`` with two deliberate differences that
 make the per-operator account exact:
 
-  * layers are compiled UNROLLED (``cfg.scan_layers=False`` is forced):
-    XLA's cost analysis counts a scan body once, so totals from a
-    scanned compile are per-layer-scale, not per-step;
+  * layers are compiled UNROLLED (``core/ffn`` always unrolls the
+    paper-FFN stack): XLA's cost analysis counts a scan body once, so
+    totals from a scanned compile would be per-layer-scale, not
+    per-step;
   * input gradients are requested too: the analytic Table II schedule
     charges every layer an AG fwd + RS bwd, but the first layer's
     backward collective (and its input-grad GEMM) is dead code when the
@@ -39,7 +40,6 @@ from repro.telemetry.predict import ffn_step_prediction
 def make_ffn_probe_step(cfg, mesh, global_batch: int):
     """Returns (jit probe_fn(params, x, y) -> (loss, grads), decls)."""
     from repro.core.ffn import ffn_apply, ffn_decls
-    cfg = cfg.replace(scan_layers=False)
     axes = MeshAxes.from_mesh(mesh)
     decls = ffn_decls(cfg, axes)
     n = cfg.ffn_width
@@ -66,10 +66,10 @@ def make_ffn_probe_step(cfg, mesh, global_batch: int):
 
 def make_ffn_pipeline_probe_step(cfg, mesh, global_batch: int):
     """Pipelined analogue of ``make_ffn_probe_step``: the 1F1B wavefront
-    with the tick loop AND the per-stage layer loops unrolled, input
-    grads kept — so the lowered HLO contains every wavefront tick's
-    collectives (XLA counts a scanned tick body once, exactly like the
-    layer scan) and the ppermute count is deterministic."""
+    with the tick loop (``scan_layers=False``) and the per-stage layer
+    loops (always) unrolled, input grads kept — so the lowered HLO
+    contains every wavefront tick's collectives (XLA counts a scanned
+    tick body once) and the ppermute count is deterministic."""
     from repro.core.ffn import ffn_decls, make_ffn_stage_fn
     from repro.train.pipeline import pipeline_run, split_microbatches
     cfg = cfg.replace(scan_layers=False)

@@ -156,3 +156,76 @@ def test_compressed_dp_training_converges(mesh24):
             first = float(loss)
         last = float(loss)
     assert last < 0.8 * first, (first, last)
+
+
+def _scanned_train_step(cfg, mesh, opt, batch):
+    """``make_ffn_train_step`` with its layers in a ``lax.scan``: one
+    loop body for every layer, the reference the unrolled stack must
+    match."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.ffn import ffn_decls, ffn_strategy
+    from repro.parallel.axes import MeshAxes, resolve_spec
+    from repro.parallel.params import specs
+
+    axes = MeshAxes.from_mesh(mesh)
+    st = ffn_strategy(cfg, axes.tp)
+    decls = ffn_decls(cfg, axes)
+    opt_decls = opt.state_decls(decls)
+
+    def step_fn(params, opt_state, step, x, y):
+        def loss_fn(p):
+            def body(h, layer):
+                return jax.nn.relu(st.apply_shard(layer, h, axes)), None
+            out, _ = lax.scan(body, x, p["layers"])
+            return jnp.sum(jnp.square(out - y)) / (batch * cfg.ffn_width)
+
+        sse_local, grads = jax.value_and_grad(loss_fn)(params)
+        loss = lax.psum(sse_local, axes.all_names)
+        grads = jax.tree.map(lambda g: lax.psum(g, axes.dp_names), grads)
+        params, opt_state = opt.update(grads, opt_state, params, step)
+        return params, opt_state, loss
+
+    pspecs = jax.tree.map(lambda s: resolve_spec(s, axes), specs(decls))
+    ospecs = jax.tree.map(lambda s: resolve_spec(s, axes), specs(opt_decls))
+    bspec = resolve_spec(P("dp", "tp"), axes)
+    return jax.jit(shard_map(
+        step_fn, mesh=mesh, in_specs=(pspecs, ospecs, P(), bspec, bspec),
+        out_specs=(pspecs, ospecs, P()), check_vma=False))
+
+
+@pytest.mark.parametrize("plan,tp", [("tensor", 1), ("phantom", 4)])
+def test_unrolled_layers_match_a_scanned_stack(plan, tp):
+    """The unrolled layer stack trains exactly as a scanned one: the bf16
+    operands are the same rounding of the same f32 weights, taken in
+    another place.  Three AdamW steps, losses and every parameter."""
+    from repro.configs.base import dense_projection_map
+    from repro.configs.paper_ffn import config
+    from repro.launch.mesh import make_local_mesh
+    from repro.optim import AdamW
+
+    n, batch = 256, 16
+    cfg = config("paper-ffn-16k").replace(d_model=n, ffn_width=n)
+    if plan == "tensor":
+        cfg = cfg.replace(projections=dense_projection_map())
+    mesh = make_local_mesh(1, tp)
+    opt = AdamW(0.3 / n, weight_decay=0.0)
+    step, _, _ = make_ffn_train_step(cfg, mesh, opt, batch)
+    scanned = _scanned_train_step(cfg, mesh, opt, batch)
+
+    params, opt_state = init_ffn(cfg, mesh, opt, seed=3)
+    runs = {}
+    for name, fn in (("unrolled", step), ("scanned", scanned)):
+        p, o = jax.tree.map(jnp.copy, (params, opt_state))
+        ds = TeacherDataset(n, batch, seed=5)
+        losses = []
+        for s in range(3):
+            p, o, loss = fn(p, o, jnp.int32(s), *ds(s))
+            losses.append(float(loss))
+        runs[name] = losses, p
+    np.testing.assert_allclose(runs["unrolled"][0], runs["scanned"][0],
+                               rtol=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-6, atol=0),
+        runs["unrolled"][1], runs["scanned"][1])

@@ -9,6 +9,7 @@ time may hold.  The persistent compilation cache is off around these
 tests, because a compile for a described chip cannot be read back.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -86,24 +87,19 @@ def test_flash_attention_gqa_compiles(one_chip, dtype):
     assert "tpu_custom_call" in txt
 
 
-def test_phantom_pallas_train_step_compiles(topo, monkeypatch):
-    """One paper-ffn-16k phantom p=4 train step with the fused Pallas
-    kernels, on a 1x4 mesh of the described chips."""
-    import repro.kernels.ops as ops
-    from repro.configs.base import with_kernel_backend
-    from repro.configs.paper_ffn import config
+def _compile_ffn_step(topo, cfg, chips, batch):
+    """The paper-FFN train step with AdamW, compiled for a 1 x ``chips``
+    mesh of the described chips; returns it and its parameters' shapes
+    on one device."""
     from repro.core.ffn import abstract_ffn, ffn_decls, make_ffn_train_step
     from repro.optim import AdamW
     from repro.parallel.axes import MeshAxes, resolve_spec
     from repro.parallel.params import specs
 
-    # the process's backend is the CPU, so the kernels would default to
-    # the interpreter; this compile is for the chip
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
-    cfg = with_kernel_backend(config("paper-ffn-16k"), "pallas")
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
     opt = AdamW(1e-5, weight_decay=0.0)
-    step, decls, opt_decls = make_ffn_train_step(cfg, mesh, opt, M)
+    step, decls, opt_decls = make_ffn_train_step(cfg, mesh, opt, batch)
 
     axes = MeshAxes.from_mesh(mesh)
 
@@ -114,14 +110,76 @@ def test_phantom_pallas_train_step_compiles(topo, monkeypatch):
             abstract_tree, specs(decl_tree))
 
     a_params, a_opt = abstract_ffn(cfg, mesh, opt)
-    batch = _sds((M, cfg.ffn_width), jnp.float32,
-                 NamedSharding(mesh, resolve_spec(P("dp", "tp"), axes)))
+    params = place(a_params, ffn_decls(cfg, axes))
+    xs = _sds((batch, cfg.ffn_width), jnp.float32,
+              NamedSharding(mesh, resolve_spec(P("dp", "tp"), axes)))
     compiled = step.lower(
-        place(a_params, ffn_decls(cfg, axes)),
-        place(a_opt, opt_decls),
-        _sds((), jnp.int32, NamedSharding(mesh, P())),
-        batch, batch).compile()
+        params, place(a_opt, opt_decls),
+        _sds((), jnp.int32, NamedSharding(mesh, P())), xs, xs).compile()
+    local = [tuple(a.sharding.shard_shape(a.shape))
+             for a in jax.tree.leaves(params)]
+    return compiled, local
+
+
+def test_phantom_pallas_train_step_compiles(topo, monkeypatch):
+    """One paper-ffn-16k phantom p=4 train step with the fused Pallas
+    kernels, on a 1x4 mesh of the described chips."""
+    import repro.kernels.ops as ops
+    from repro.configs.base import with_kernel_backend
+    from repro.configs.paper_ffn import config
+
+    # the process's backend is the CPU, so the kernels would default to
+    # the interpreter; this compile is for the chip
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = with_kernel_backend(config("paper-ffn-16k"), "pallas")
+    compiled, _ = _compile_ffn_step(topo, cfg, 4, M)
     assert "tpu_custom_call" in compiled.as_text()
     # fits one v5e chip's 16 GB with room to spare (~0.9 GiB per device)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 4 * 2**30
+
+
+def _entry(hlo: str):
+    """The instructions of the module's entry computation."""
+    lines = hlo.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith("ENTRY "))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1:end]
+
+
+def _shape(instr: str):
+    """The dimensions of an instruction's (array) result."""
+    m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]", instr)
+    return tuple(int(d) for d in m.group(1).split(",") if d) if m else None
+
+
+@pytest.mark.parametrize("plan,chips,temp_limit", [
+    ("tensor", 1, 2.5 * 2**30),
+    ("phantom", 4, 128 * 2**20),
+])
+def test_ffn_train_step_runs_its_layers_unrolled(topo, plan, chips,
+                                                 temp_limit):
+    """The paper-ffn-16k train step (batch 256, the XLA path) compiled
+    for the chip keeps no whole-stack work: no cast of the stacked
+    weights to bf16 and no zero fill of a stacked gradient in the entry
+    computation, no layer loop, and the dense step's five products (two
+    forward, two weight and one input gradient; the first layer's input
+    gradient is dead)."""
+    from repro.configs.base import dense_projection_map, with_kernel_backend
+    from repro.configs.paper_ffn import config
+
+    cfg = with_kernel_backend(config("paper-ffn-16k"), "xla")
+    if plan == "tensor":
+        cfg = cfg.replace(projections=dense_projection_map())
+    compiled, stacked = _compile_ffn_step(topo, cfg, chips, M)
+    assert all(s[0] == cfg.num_layers == 2 for s in stacked)
+    hlo = compiled.as_text()
+    whole_stack = [i.strip()[:120] for i in _entry(hlo)
+                   if re.search(r" (convert|broadcast)\(", i)
+                   and _shape(i) in stacked]
+    assert whole_stack == []
+    assert " while(" not in hlo
+    if plan == "tensor":
+        assert len(re.findall(r" convolution\(", hlo)) == 5
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
