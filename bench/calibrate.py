@@ -57,8 +57,7 @@ def calibrate(cell, seeds, control_seeds, log=print):
         if cell.chips > 1:
             fs["no_exchange"] = compare.numbers(harness.reference_steps(
                 cell, tp, seed, devices[0],
-                layer=faults.no_exchange(cell.config["projection"], tp)),
-                ref)
+                **cell.program.no_exchange(cell.config, tp)), ref)
         out["faults"][seed] = fs
         log(f"seed {seed} faults {fs}")
     for part in ("program", "control"):

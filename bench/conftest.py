@@ -18,17 +18,16 @@ import pytest  # noqa: E402
 
 from bench import spec  # noqa: E402
 
-TINY_WIDTH, TINY_K, TINY_BATCH = 128, 4, 16
+TINY_BATCH = 16
 
 
 def tiny(cell_name: str, chips: int = None):
-    """A cell at n=128, k=4, batch 16, with the cell's own limits."""
+    """A cell at its program's tiny size (``tiny_config``) and batch 16,
+    with the cell's own limits."""
     cell = spec.load_cell(cell_name)
-    cfg = dict(cell.config, ffn_width=TINY_WIDTH)
-    if cfg["projection"] == "phantom":
-        cfg["phantom"] = dict(cfg["phantom"], k=TINY_K)
     return dataclasses.replace(
-        cell, config=cfg, chips=chips or cell.chips,
+        cell, config=cell.program.tiny_config(cell.config),
+        chips=chips or cell.chips,
         traffic=dict(cell.traffic, global_batch=TINY_BATCH))
 
 

@@ -20,7 +20,9 @@ and the few fields of XLA's ``xplane.proto`` named in ``_SCHEMA``.
 ``Reduced`` clips every operation to the window, gives each instant to
 the innermost operation running then, classes it as matmul, collective
 or vector (everything else), and gives the per-step times that the
-readers in ``bench/metrics/`` report.
+readers in ``bench/metrics/`` report; the step's operation count comes
+from the cell's program module, and each op's part of the step
+(forward, backward, optimizer) from the map of ``bench/scopes.py``.
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ import collections
 import glob
 import os
 
-from bench import flops
+from bench import scopes as scope_map
 
 HOST_SPANS = ("bench.window", "bench.select", "bench.dispatch", "bench.wait")
 # XLA's op categories on the TPU's "XLA Ops" line, by class
@@ -297,11 +299,14 @@ def _innermost(ops):
 
 
 class Reduced:
-    """Per-chip, per-step times of one traced window."""
+    """Per-chip, per-step times of one traced window of a cell's run.
+    ``scopes`` is the map from op name to part of the step that
+    ``scopes.program_scopes`` makes; one that names no scope leaves the
+    scope readers at None."""
 
-    def __init__(self, events: dict, *, cfg: dict, tp: int, batch: int,
-                 peak: dict):
-        self.cfg, self.tp, self.batch, self.peak = cfg, tp, batch, peak
+    def __init__(self, events: dict, cell, *, peak: dict, scopes: dict):
+        self.cell, self.peak, self.scopes = cell, peak, scopes
+        self.tp = cell.program.mesh_tp(cell.config, cell.chips)
         self.w0, self.w1 = events["window"]
         self.steps = events["steps"]
         self.host = events["host"]
@@ -353,10 +358,13 @@ class Reduced:
         return 1e-6 * sum(d) / len(d) if d else None
 
     def step_flops(self) -> float:
-        return flops.step_flops(self.cfg, self.tp, self.batch)
+        c = self.cell
+        return c.program.step_flops(c.config, self.tp, c.traffic)
 
     def matmul_floor_s(self):
-        return flops.matmul_floor_s(self.cfg, self.tp, self.batch, self.peak)
+        c = self.cell
+        return c.program.matmul_floor_s(c.config, self.tp, c.traffic,
+                                        self.peak)
 
     def _host_at(self, t):
         for s, d, name in self.host:
@@ -365,10 +373,16 @@ class Reduced:
         return "host_other"
 
     def breakdown(self, top: int = 10) -> dict:
+        """The device ops that took most time, each named with its part
+        of the step (``optimizer:fusion.20``) where the map names a
+        scope, and the longest idle gaps by what the host was doing."""
         chips = len(self.ops)
+        named = scope_map.names_a_scope(self.scopes)
         by_op = collections.Counter()
         for ops in self.ops:
             for s, e, name, _ in ops:
+                if named:
+                    name = f"{self.scopes.get(name, 'unscoped')}:{name}"
                 by_op[name] += (e - s) * 1e-9 / chips
         gaps = []
         for d, busy in enumerate(self.busy):

@@ -8,7 +8,9 @@ in flight, the host time at which each step's loss is ready recorded,
 no step waited on twice; when the time is up nothing more is sent, and
 the window closes once every step sent has finished.
 After the window the program's state is freed and the plain reference
-(``bench/reference.py``) follows the first steps on one chip.
+follows the first steps on one chip.  Everything that belongs to one
+model, its step, inputs, reference and operation count, comes from the
+cell's program module (``spec.py`` lists what the harness calls).
 """
 from __future__ import annotations
 
@@ -24,8 +26,7 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import compare, data, devtrace, flops, reference
-from bench.program import Program, mesh_tp
+from bench import compare, data, devtrace, scopes
 
 TRACE_SECONDS = 2.0     # longest traced window
 WARM_SECONDS = 0.25     # warm-up after the compared steps, two in flight
@@ -72,9 +73,10 @@ def run_loop(prog, state, pool, step, seconds, ahead=2):
     with TraceAnnotation("bench.window"):
         while True:
             with TraceAnnotation("bench.select"):
-                x, y = pool[step % len(pool)]
+                batch = pool[step % len(pool)]
             with TraceAnnotation("bench.dispatch"):
-                params, opt_state, loss = prog(params, opt_state, step, x, y)
+                params, opt_state, loss = prog(params, opt_state, step,
+                                               *batch)
             inflight.append(loss)
             step += 1
             if len(inflight) >= ahead:
@@ -101,9 +103,9 @@ def build(cell):
     """The program's compiled step for a cell, its tp and its chips."""
     from repro.launch.mesh import make_local_mesh
 
-    tp = mesh_tp(cell.config, cell.chips)
+    tp = cell.program.mesh_tp(cell.config, cell.chips)
     mesh = make_local_mesh(1, cell.chips)
-    prog = Program(cell.config, cell.traffic["global_batch"], mesh)
+    prog = cell.program.build(cell.config, cell.traffic, mesh)
     return prog, tp, list(mesh.devices.flat)
 
 
@@ -111,9 +113,9 @@ def first_steps(cell, prog, tp, seed):
     """Feed the program the seed's weights and batch pool, and drive it
     through the compared first steps on the window's own call and feed.
     Returns the state, the pool, and the numbers the comparison reads."""
-    cfg = cell.config
+    cfg, program = cell.config, cell.program
     key = data.root_key(seed)
-    params = jax.jit(lambda k: data.init_params(cfg, tp, k),
+    params = jax.jit(lambda k: program.init_params(cfg, tp, k),
                      out_shardings=prog.param_sharding)(key)
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     want = jax.tree.map(lambda a: (a.shape, a.dtype), prog.abstract_params)
@@ -121,38 +123,36 @@ def first_steps(cell, prog, tp, seed):
         raise ValueError(f"the program's parameters are {want}, the "
                          f"benchmark makes {got}")
     opt_state = prog.init_opt(params)
-    flat = jax.jit(lambda k: data.teacher_pool(cfg, cell.traffic, k))(key)
-    flat = jax.device_put(flat, prog.batch_sharding)
-    pool = list(zip(flat[0::2], flat[1::2]))
-    del flat
+    pool = jax.jit(lambda k: program.batch_pool(cfg, cell.traffic, k))(key)
+    pool = [tuple(b) for b in jax.device_put(pool, prog.batch_sharding)]
 
-    norms = jax.jit(lambda t: reference.leaf_norms(cfg, tp, t))
-    change = jax.jit(lambda t, k: reference.change_norms(cfg, tp, t, k))
+    norms = jax.jit(lambda t: program.leaf_norms(cfg, tp, t))
+    change = jax.jit(lambda t, k: program.change_norms(cfg, tp, t, k))
     losses = []
-    for s in range(reference.FIRST_STEPS):
+    for s in range(program.FIRST_STEPS):
         params, opt_state, loss = prog(params, opt_state, s, *pool[s])
         losses.append(loss)
         if s == 0:
-            grad_norms = norms(opt_state["m"]["layers"])
+            grad_norms = norms(program.grad_tree(opt_state))
     numbers = {"losses": [float(a) for a in losses],
                "grad_norms": [float(a) / (1 - prog.b1) for a in grad_norms],
                "change_norms": [float(a) for a in
-                                change(params["layers"], key)]}
+                                change(program.param_tree(params), key)]}
     return (params, opt_state), pool, numbers
 
 
 def reference_steps(cell, tp, seed, device, rows=None, **kw):
     """The reference's numbers on one chip, from the seed's weights and
-    the same batches, or their first ``rows`` rows (``reference.
-    first_steps`` takes ``kw``)."""
-    cfg = cell.config
+    the same batches, or their first ``rows`` rows (the program's
+    ``first_steps`` takes ``kw``)."""
+    cfg, program = cell.config, cell.program
     with jax.default_device(device):
-        flat = jax.jit(lambda k: data.teacher_pool(cfg, cell.traffic, k))(
+        pool = jax.jit(lambda k: program.batch_pool(cfg, cell.traffic, k))(
             data.root_key(seed))
-        batches = [(x[:rows], y[:rows]) for x, y in
-                   zip(flat[0::2], flat[1::2])][:reference.FIRST_STEPS]
-        del flat
-        return reference.first_steps(cfg, tp, seed, batches, **kw)
+        batches = [tuple(a[:rows] for a in b)
+                   for b in pool[:program.FIRST_STEPS]]
+        del pool
+        return program.first_steps(cfg, tp, seed, batches, **kw)
 
 
 def run(cell, seed: int, seconds: float, trace: bool, *, peak: dict,
@@ -165,7 +165,8 @@ def run(cell, seed: int, seconds: float, trace: bool, *, peak: dict,
     state, pool, prog_first = first_steps(cell, prog, tp, seed)
     marks.append(("first steps", time.perf_counter()))
     state, t0, done, _, step = run_loop(prog, state, pool,
-                                        reference.FIRST_STEPS, WARM_SECONDS)
+                                        cell.program.FIRST_STEPS,
+                                        WARM_SECONDS)
     marks.append(("warm-up", time.perf_counter()))
     print("set-up: " + ", ".join(
         f"{name} {t - t_prev:.2f} s" for (name, t), t_prev in
@@ -202,7 +203,6 @@ def run(cell, seed: int, seconds: float, trace: bool, *, peak: dict,
                          "kind": devices[0].device_kind,
                          "count": len(devices),
                          "memory_peak_bytes": int(mem_peak)}}
-    step_flops = flops.step_flops(cfg, tp, batch)
     if trace:
         try:
             events = devtrace.load(tdir, len(devices))
@@ -210,8 +210,10 @@ def run(cell, seed: int, seconds: float, trace: bool, *, peak: dict,
                 devtrace.trim(tdir, keep_trace)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
-        red = devtrace.Reduced(events, cfg=cfg, tp=tp, batch=batch,
-                               peak=peak)
+        scope_map = scopes.program_scopes(cell)
+        if keep_trace:
+            scopes.write_map(scopes.map_path(keep_trace), cell, scope_map)
+        red = devtrace.Reduced(events, cell, peak=peak, scopes=scope_map)
         result["device"].update(busy_s=red.busy_s, window_s=red.window_s)
         for entry, mod in cell.per_layer:
             v = mod.read(red)
@@ -222,6 +224,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, peak: dict,
     else:
         span = done[-1] - t0
         durs = np.diff([t0] + done)
+        step_flops = cell.program.step_flops(cfg, tp, cell.traffic)
         e2e = {"samples_per_s": steps * batch / span,
                "step_ms_p95": 1e3 * p95(durs),
                "mfu": 100.0 * step_flops * steps / span

@@ -1,7 +1,8 @@
 """Share of its roofline that the step's matmul time reaches: the least
-time one chip can spend on the step's required products (``bench/
-flops.py``: each product at the longer of its FLOPs at the bf16 peak and
-its least bytes at the HBM peak) over the measured matmul time."""
+time one chip can spend on the step's required products (the cell's
+program's ``matmul_floor_s``: each product at the longer of its FLOPs at
+the bf16 peak and its least bytes at the HBM peak) over the measured
+matmul time."""
 UNIT, LAYER, MOVES = "%", "kernels", "mfu"
 
 

@@ -33,7 +33,10 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--keep-trace", metavar="PATH",
                     help="with --trace 1, also write the profiler's "
-                         "trace to PATH, cut to what the reduction reads")
+                         "trace to PATH, cut to what the reduction reads, "
+                         "and the map of its op names to parts of the "
+                         "step beside it (PATH's .xplane.pb -> "
+                         ".scopes.json)")
     args = ap.parse_args(argv)
 
     src = os.path.join(ROOT, "src")

@@ -14,12 +14,13 @@ map from instruction name to class (``classes``) classes every
 innermost-op interval that ``devtrace`` computes.
 
 The map's source is the program's own compiled step,
-``Compiled.as_text()``: the harness frees its step before the readers
-run, so ``program_scopes`` compiles it again as the harness does (the
+``Compiled.as_text()``: the harness frees its step before it reduces the
+trace, so ``program_scopes`` compiles it again as the harness does (the
 compiler names the instructions of one program alike on every build,
-and a step that differs only in metadata alike too).  A trace kept
-with ``--keep-trace`` is reduced again offline with the map that ``map``
-writes beside it::
+and a step that differs only in metadata alike too), and the harness
+hands the map to ``devtrace.Reduced``.  ``--keep-trace`` writes the map
+beside the trace it keeps (``map_path``), and ``map`` writes one for a
+trace kept another way; ``reduce`` reduces a kept trace again offline::
 
   python3 bench/scopes.py map --workload <cell> --out <trace>.scopes.json
   python3 bench/scopes.py reduce --workload <cell> --trace <trace> \\
@@ -118,11 +119,16 @@ def classes(hlo_text: str) -> dict:
     return out
 
 
+def names_a_scope(scopes) -> bool:
+    """Whether a map classes some instruction as a part of the step."""
+    return bool(scopes) and bool(set(scopes.values()) & set(SCOPED))
+
+
 def scope_s(r, scopes: dict):
     """Device seconds in the traced window per class, mean over chips,
     from ``r``'s innermost-op pieces (``devtrace.Reduced.ops``); None
     where the map classes no instruction as a scope."""
-    if not scopes or not set(scopes.values()) & set(SCOPED):
+    if not names_a_scope(scopes):
         return None
     chips = len(r.ops)
     out = collections.Counter({c: 0.0 for c in CLASSES})
@@ -132,18 +138,8 @@ def scope_s(r, scopes: dict):
     return out
 
 
-def breakdown(r, scopes: dict, top: int = 10) -> dict:
-    """``r.breakdown``, each device op's name prefixed with its class
-    (``optimizer:fusion.20``) where the map names a scope."""
-    b = r.breakdown(top)
-    if scope_s(r, scopes) is not None:
-        b["device_ops"] = [[f"{scopes.get(n, 'unscoped')}:{n}", s]
-                           for n, s in b["device_ops"]]
-    return b
-
-
-def program_scopes(cfg: dict, batch: int, chips: int) -> dict:
-    """The map of the program's compiled step for a cell, built on this
+def program_scopes(cell) -> dict:
+    """The map of a cell's compiled step, built by its program on this
     machine's chips as the harness builds it, past the persistent
     compile cache: the cache's key leaves out HLO metadata, so a cached
     step may carry the ``op_name``s of another program with the same
@@ -151,52 +147,55 @@ def program_scopes(cfg: dict, batch: int, chips: int) -> dict:
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    from bench.program import Program
     from repro.launch.mesh import make_local_mesh
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        prog = Program(cfg, batch, make_local_mesh(1, chips))
+        prog = cell.program.build(cell.config, cell.traffic,
+                                  make_local_mesh(1, cell.chips))
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
     return classes(prog.compiled.as_text())
 
 
+def map_path(trace: str) -> str:
+    """Where the map of a trace kept at ``trace`` goes:
+    ``<name>.xplane.pb`` -> ``<name>.scopes.json``."""
+    base = trace[:-len(".xplane.pb")] if trace.endswith(".xplane.pb") \
+        else trace
+    return base + ".scopes.json"
+
+
+def write_map(path: str, cell, scopes: dict):
+    import jax
+
+    with open(path, "w") as f:
+        json.dump({"workload": cell.name,
+                   "device": jax.devices()[0].device_kind,
+                   "scopes": scopes}, f, indent=0, sort_keys=True)
+
+
 def read(r, cls: str):
     """A reader's number: device time per step in one class, ms, by the
-    map that ``r`` keeps, else by the program's own, which it then
-    keeps for the next reader."""
-    if getattr(r, "scopes", None) is None:
-        r.scopes = program_scopes(r.cfg, r.batch, len(r.ops))
+    map that ``r`` keeps."""
     s = scope_s(r, r.scopes)
     return None if s is None else r.per_step_ms(s[cls])
 
 
-def reduce(events: dict, **kw):
-    """``devtrace.Reduced`` of ``events``, keeping the map that the
-    events carry under ``scopes`` (none: every reader reads None)."""
+def _kept(cell, trace: str, map_file: str, peak: dict) -> dict:
     from bench import devtrace
 
-    r = devtrace.Reduced(events, **kw)
-    r.scopes = events.get("scopes", {})
-    return r
-
-
-def _kept(cell, trace: str, map_path: str, peak: dict) -> dict:
-    from bench import devtrace
-
-    events = devtrace.load(trace, cell.chips)
-    with open(map_path) as f:
-        events["scopes"] = json.load(f)["scopes"]
-    r = reduce(events, cfg=cell.config, tp=cell.config["tp"] or cell.chips,
-               batch=cell.traffic["global_batch"], peak=peak)
+    with open(map_file) as f:
+        scopes = json.load(f)["scopes"]
+    r = devtrace.Reduced(devtrace.load(trace, cell.chips), cell, peak=peak,
+                         scopes=scopes)
     s = scope_s(r, r.scopes) or {}
     return {"steps": r.steps, "busy_ms": r.per_step_ms(r.busy_s),
             "scope_ms": {c: r.per_step_ms(v) for c, v in s.items()},
-            "breakdown": breakdown(r, r.scopes, top=20)}
+            "breakdown": r.breakdown(top=20)}
 
 
 def main(argv=None) -> int:
@@ -219,14 +218,7 @@ def main(argv=None) -> int:
 
     cell = spec.load_cell(args.workload)
     if args.cmd == "map":
-        import jax
-
-        scopes = program_scopes(cell.config, cell.traffic["global_batch"],
-                                cell.chips)
-        with open(args.out, "w") as f:
-            json.dump({"workload": cell.name,
-                       "device": jax.devices()[0].device_kind,
-                       "scopes": scopes}, f, indent=0, sort_keys=True)
+        write_map(args.out, cell, program_scopes(cell))
         return 0
     with open(os.path.join(root, "bench", "peaks.json")) as f:
         peak = next(iter(json.load(f).values()))

@@ -12,7 +12,6 @@ import pytest
 
 from bench import devtrace, scopes, spec
 from bench.conftest import tiny
-from bench.program import Program
 
 READERS = ("forward_ms", "backward_ms", "optimizer_ms")
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -28,10 +27,11 @@ SCOPED_METRICS = {"forward_ms": 0.4073558850800192,
                   "optimizer_ms": 1.4270115765800264}
 
 
-def _reduce(events, cell="phantom16k-p4-b256"):
-    c = spec.load_cell(cell)
-    return scopes.reduce(events, cfg=c.config, tp=c.config["tp"] or c.chips,
-                         batch=c.traffic["global_batch"], peak=PEAK)
+def _reduce(events, cell="phantom16k-p4-b256", scope_map=None):
+    """The reduction with a map, as the harness makes it (none: an empty
+    one, as a program that names no scope gives)."""
+    return devtrace.Reduced(events, spec.load_cell(cell), peak=PEAK,
+                            scopes=scope_map or {})
 
 
 def _read(r):
@@ -123,14 +123,14 @@ def test_readers_on_hand_made_events():
     """Each class's innermost-op time per step; an op the map does not
     name is unscoped; the four classes sum to the busy time; each top
     op is named with its class."""
-    r = _reduce(_events(HAND_MADE, scopes=HAND_MAP))
+    r = _reduce(_events(HAND_MADE), scope_map=HAND_MAP)
     assert _read(r) == pytest.approx({"forward_ms": 10e-6,
                                       "backward_ms": 15e-6,
                                       "optimizer_ms": 12.5e-6})
     s = scopes.scope_s(r, r.scopes)
     assert s["unscoped"] == pytest.approx(15e-9)
     assert sum(s.values()) == pytest.approx(r.busy_s)
-    ops = dict(scopes.breakdown(r, r.scopes)["device_ops"])
+    ops = dict(r.breakdown()["device_ops"])
     assert ops == pytest.approx({"forward:fusion.1": 20e-9,
                                  "backward:fusion.2": 30e-9,
                                  "optimizer:fusion.3": 25e-9,
@@ -144,23 +144,24 @@ def test_a_loop_and_several_chips():
           "devices": [[[0, 60, "while.4", "while"],
                        [10, 20, "fusion.1", "convolution fusion"],
                        [60, 20, "fusion.20", "loop fusion"]],
-                      [[0, 40, "fusion.20", "loop fusion"]]],
-          "scopes": {"while.4": "forward", "fusion.1": "backward",
-                     "fusion.20": "optimizer"}}
-    r = _reduce(ev, "dense16k-b256")
+                      [[0, 40, "fusion.20", "loop fusion"]]]}
+    r = _reduce(ev, "dense16k-b256", {"while.4": "forward",
+                                      "fusion.1": "backward",
+                                      "fusion.20": "optimizer"})
     assert _read(r) == pytest.approx({"forward_ms": 20e-6,
                                       "backward_ms": 10e-6,
                                       "optimizer_ms": 30e-6})
 
 
-@pytest.mark.parametrize("kw", [{}, {"scopes": {}},
-                                {"scopes": {"fusion.1": "unscoped"}}])
+@pytest.mark.parametrize("kw", [{}, {"scope_map": {}},
+                                {"scope_map": {"fusion.1": "unscoped"}}])
 def test_no_map_reads_none(kw):
     """Without a map that names a scope (a program older than the
     scopes), every reader reads None and op names stay as they are."""
-    r = _reduce(_events(HAND_MADE, **kw))
+    r = _reduce(_events(HAND_MADE), **kw)
     assert _read(r) == dict.fromkeys(READERS)
-    assert scopes.breakdown(r, r.scopes) == r.breakdown()
+    assert [n for n, _ in r.breakdown()["device_ops"]] == [
+        n for n, _ in sorted(HAND_MADE, key=lambda o: -o[1])]
 
 
 def test_scope_names_match_the_program():
@@ -209,8 +210,8 @@ def _lm_step_text():
 def _ffn_step_text(name, chips):
     cell = tiny(name, chips)
     from repro.launch.mesh import make_local_mesh
-    prog = Program(cell.config, cell.traffic["global_batch"],
-                   make_local_mesh(1, chips))
+    prog = cell.program.build(cell.config, cell.traffic,
+                              make_local_mesh(1, chips))
     return prog.compiled.as_text()
 
 
@@ -227,17 +228,16 @@ def test_program_steps_name_every_op(step):
 
 
 def test_readers_build_the_program_map():
-    """Where the reduction carries no map, as in the harness's run, the
-    readers build the program's step again and class its op names."""
+    """The map that the harness builds from the program's step again
+    after the window classes the step's op names for the readers."""
     cell = tiny("dense16k-b256", 1)
-    cfg, batch = cell.config, cell.traffic["global_batch"]
     first = {}
-    for name, cls in scopes.program_scopes(cfg, batch, 1).items():
+    for name, cls in scopes.program_scopes(cell).items():
         first.setdefault(cls, name)
     ops = [(first["forward"], 20), (first["backward"], 30),
            (first["optimizer"], 40)]
-    r = devtrace.Reduced(_events(ops), cfg=cfg, tp=1, batch=batch,
-                         peak=PEAK)
+    r = devtrace.Reduced(_events(ops), cell, peak=PEAK,
+                         scopes=scopes.program_scopes(cell))
     assert _read(r) == pytest.approx({"forward_ms": 10e-6,
                                       "backward_ms": 15e-6,
                                       "optimizer_ms": 20e-6})
@@ -272,25 +272,24 @@ def test_map_is_built_past_the_compile_cache(compile_cache, monkeypatch):
     from repro.obs import scopes as program
 
     cell = tiny("dense16k-b256", 1)
-    cfg, batch = cell.config, cell.traffic["global_batch"]
     with monkeypatch.context() as m:
         m.setattr(program, "MODEL", "unnamed")
         m.setattr(program, "OPTIMIZER", "unnamed_update")
-        older = Program(cfg, batch, make_local_mesh(1, 1))
+        older = cell.program.build(cell.config, cell.traffic,
+                                   make_local_mesh(1, 1))
     assert set(scopes.classes(older.compiled.as_text()).values()) == {
         "unscoped"}
     assert os.listdir(compile_cache)
-    assert set(scopes.SCOPED) <= set(
-        scopes.program_scopes(cfg, batch, 1).values())
+    assert set(scopes.SCOPED) <= set(scopes.program_scopes(cell).values())
 
 
 def test_recorded_scoped_phantom_trace():
     """The three scopes of a recorded trace of the scoped program, to
     the digit; together they cover at least 98% of the busy time."""
     cell = spec.load_cell("phantom16k-p4-b256")
-    events = devtrace.load(SCOPED + ".xplane.pb", cell.chips)
-    with open(SCOPED + ".scopes.json") as f:
-        events["scopes"] = json.load(f)["scopes"]
-    r = _reduce(events)
+    with open(scopes.map_path(SCOPED + ".xplane.pb")) as f:
+        scope_map = json.load(f)["scopes"]
+    r = _reduce(devtrace.load(SCOPED + ".xplane.pb", cell.chips),
+                scope_map=scope_map)
     assert _read(r) == SCOPED_METRICS
     assert sum(SCOPED_METRICS.values()) >= 0.98 * r.per_step_ms(r.busy_s)

@@ -1,38 +1,41 @@
 """The reduction from trace events to per-layer numbers: on hand-made
 events whose answers are known, and on a trace recorded on the chip."""
+import json
 import os
 
 import pytest
 
-from bench import devtrace, spec
+from bench import devtrace, scopes, spec
 
 # A 0.05 s window of phantom16k-p4-b256 on a TPU v5e 2x2, cut by
-# ``bench/run.py --keep-trace``
+# ``bench/run.py --keep-trace``, and the map of the step's op names to
+# its parts, made on the same machine
 RECORDED = os.path.join(os.path.dirname(__file__), "testdata",
-                        "phantom16k-p4-b256.xplane.pb")
+                        "phantom16k-p4-b256-scoped.xplane.pb")
 # what the reduction read from it, pinned
 RECORDED_STEPS, RECORDED_BUSY_S, RECORDED_WINDOW_S = (
-    25, 0.06139319622400087, 0.064028509)
+    25, 0.061398795116501835, 0.063821478)
 RECORDED_METRICS = {
-    "dispatch_ms": 1.3800951999999997,
-    "vector_ms": 2.038140576339887,
-    "matmul_ms": 0.39512244680003555,
-    "matmul_roofline": 66.44373089007497,
-    "step_mfu": 9.037490334230576,
-    "collective_ms": 0.13505234845999511,
-    "exposed_collective_ms": 0.02246482581999451,
-    "idle_share": 4.1158427974624985,
+    "dispatch_ms": 1.29654564,
+    "vector_ms": 2.0383870444198995,
+    "matmul_ms": 0.3950561829000366,
+    "matmul_roofline": 66.45487568651112,
+    "step_mfu": 9.036666214852275,
+    "collective_ms": 0.13514025306000024,
+    "exposed_collective_ms": 0.022508577340001164,
+    "idle_share": 3.7960306771619545,
+    "forward_ms": 0.4073558850800192,
+    "backward_ms": 0.6177153452800072,
+    "optimizer_ms": 1.4270115765800264,
 }
-RECORDED_TOP_OP = ["multiply_subtract_fusion", 0.035406879063500006]
+RECORDED_TOP_OP = ["optimizer:multiply_subtract_fusion", 0.035409530976999996]
 
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-def _reduced(events, cell="phantom16k-p4-b256"):
-    c = spec.load_cell(cell)
-    tp = c.config["tp"] or c.chips
-    return devtrace.Reduced(events, cfg=c.config, tp=tp,
-                            batch=c.traffic["global_batch"], peak=PEAK)
+def _reduced(events, cell="phantom16k-p4-b256", scope_map=None):
+    return devtrace.Reduced(events, spec.load_cell(cell), peak=PEAK,
+                            scopes=scope_map or {})
 
 
 @pytest.mark.parametrize("name,category,cls", [
@@ -117,7 +120,9 @@ def test_recorded_phantom_trace():
     ghost all-gather is an asynchronous collective fusion in flight
     across the local product, so most of the collective time is hidden."""
     cell = spec.load_cell("phantom16k-p4-b256")
-    r = _reduced(devtrace.load(RECORDED, cell.chips))
+    with open(scopes.map_path(RECORDED)) as f:
+        scope_map = json.load(f)["scopes"]
+    r = _reduced(devtrace.load(RECORDED, cell.chips), scope_map=scope_map)
     assert (r.steps, r.busy_s, r.window_s) == (
         RECORDED_STEPS, RECORDED_BUSY_S, RECORDED_WINDOW_S)
     assert {m["name"]: mod.read(r) for m, mod in cell.per_layer} == (
